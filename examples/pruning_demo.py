@@ -15,7 +15,7 @@ from repro.configs import list_archs, smoke_variant
 from repro.configs.base import InputShape
 from repro.core import pruning as P
 from repro.kernels.block_masked_matmul.ops import masked_matmul
-from repro.models import model
+from repro.models import model, ops
 
 
 def main():
@@ -59,7 +59,8 @@ def main():
     w = jax.random.normal(jax.random.fold_in(rng, 1), (256, 256))
     cm = jnp.repeat((jax.random.uniform(rng, (2,)) > 0.5).astype(jnp.float32),
                     128)
-    y = masked_matmul(x, w, cm, jnp.ones(256))
+    y = masked_matmul(x, w, cm, jnp.ones(256),
+                      interpret=ops.pallas_interpret())
     print(f"block-masked matmul: {int(jnp.sum(cm))}/256 cols active, "
           f"out nonzero cols = "
           f"{int(jnp.sum(jnp.any(jnp.abs(y) > 0, axis=0)))}")

@@ -320,4 +320,6 @@ def main(argv: Optional[Sequence[str]] = None
 
 
 if __name__ == "__main__":
+    from repro.launch.env import enable_compile_cache
+    enable_compile_cache()
     main()

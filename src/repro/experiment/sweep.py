@@ -50,11 +50,13 @@ import os
 import re
 import time
 import traceback
+import warnings
 from typing import (Any, Dict, List, Mapping, NamedTuple, Optional, Sequence,
                     Tuple)
 
 from repro.experiment.run import checkpoint_exists, run_spec
 from repro.experiment.spec import ExperimentSpec
+from repro.launch.env import tpu_expected
 
 MANIFEST_FORMAT = 1
 MANIFEST_NAME = "sweep.json"
@@ -643,7 +645,9 @@ def _run_procs(man: dict, out: str, order: List[str],
     One spawn-context process per in-flight run (spawn, not fork:
     forking a process with a live JAX runtime deadlocks; spawn
     re-imports repro in each worker from PYTHONPATH), results returned
-    over a Pipe.  A run whose attempt exceeds ``timeout_s`` is
+    over a Pipe.  Where the workers would take a TPU, one runs at a
+    time: the parent never touches a JAX backend, and each worker holds
+    every chip of the host.  A run whose attempt exceeds ``timeout_s`` is
     terminated (then killed) and treated like a crash; crashes requeue
     with exponential backoff until ``max_retries`` attempts are
     exhausted, then quarantine as ``failed`` without stopping the grid.
@@ -652,6 +656,14 @@ def _run_procs(man: dict, out: str, order: List[str],
 
     ctx = mp.get_context("spawn")
     workers = max(max_workers or min(len(order), 4), 1)
+    if workers > 1 and tpu_expected():
+        # a chip belongs to one process, and a JAX process takes every
+        # chip of its host: a second worker would fail or hang
+        if max_workers:
+            warnings.warn(f"max_workers={max_workers} lowered to 1: the "
+                          "TPU runs one process per host",
+                          RuntimeWarning)
+        workers = 1
     # (rid, attempt, not_before): retries wait out their backoff here
     ready: List[Tuple[str, int, float]] = [(rid, 1, 0.0) for rid in order]
     running: Dict[str, dict] = {}

@@ -50,8 +50,8 @@ from repro.fl.compress import (QUANTS, downlink_bytes,
                                ef_roundtrip_jit as _ef_jit, uplink_bytes)
 from repro.fl.engine import (adam_stack_from_tree, make_round_engine,
                              resolve_engine, resolve_store, route_engine,
-                             stacked_adam_init, stacked_zeros, store_tree,
-                             tree_gather, tree_scatter)
+                             stacked_adam_init, stacked_zeros, stage_clients,
+                             store_tree, tree_gather, tree_scatter)
 from repro.fl.faults import (FaultSpec, apply_late, late_delta,
                              make_fault_model)
 from repro.fl.record import RoundRecord, RunResult, evals_of
@@ -388,8 +388,8 @@ class FlatTrainer:
                 padded = padded or not bool(prefix.all())
                 valid = valid & prefix
         with obs.span("round/h2d", round=r):
-            batches = {k: jnp.asarray(v) for k, v in batches.items()}
-            valid = jnp.asarray(valid)
+            batches, valid = stage_clients((batches, valid), self.mesh,
+                                           self.client_axis)
             rngs = jnp.stack(subs)
         # the flat topology is the E=1 special case of the edge engine
         server = jax.tree.map(lambda leaf: leaf[None], params)

@@ -211,14 +211,22 @@ def adam_stack_from_tree(t, store: str = "device") -> Optional[AdamState]:
 # ---------------------------------------------------------------------------
 
 def make_train_one(loss_fn, *, method: str = "fedphd", lr: float = 2e-4,
-                   unroll: int = 8):
+                   unroll: Optional[int] = None):
     """One client's local round as a masked scan over stacked batches.
 
     ``train_one(params, opt_state, batches, valid, rng, ctx, masked)``
     -> ``(params, opt_state, mean_loss)``.  Used under vmap by
     ``make_round_engine`` and directly (with toy loss_fns) by the
     property tests that pin the padding-mask invariant.
+
+    ``unroll`` (None = by backend): XLA:CPU runs conv/dot thunks inside
+    a while-loop body without the runtime thread pool, so block-
+    unrolling 8 steps amortizes that there; an accelerator gains
+    nothing from it and pays a compile of every unrolled copy (a
+    paper-width U-Net step is minutes of TPU compile), so it gets 1.
     """
+    if unroll is None:
+        unroll = 8 if jax.default_backend() == "cpu" else 1
     def train_one(params, opt_state, batches, valid, rng, ctx, masked):
         def body(carry, xs):
             p, o, r = carry
@@ -235,10 +243,7 @@ def make_train_one(loss_fn, *, method: str = "fedphd", lr: float = 2e-4,
                 new_o = jax.tree.map(keep, new_o, o)
                 loss = jnp.where(v, loss, 0.0)
             return (new_p, new_o, r), loss
-        # unroll: XLA:CPU runs conv/dot thunks inside a while-loop body
-        # without the runtime thread pool; block-unrolling a few steps
-        # amortizes that penalty at modest compile-time cost (full
-        # unroll explodes compile time for long rounds)
+        # full unroll explodes compile time for long rounds
         (params, opt_state, _), losses = jax.lax.scan(
             body, (params, opt_state, rng), (batches, valid),
             unroll=min(unroll, valid.shape[0]))
@@ -251,7 +256,8 @@ def make_train_one(loss_fn, *, method: str = "fedphd", lr: float = 2e-4,
 
 def make_round_engine(cfg: ModelConfig, fl: FLConfig, *,
                       method: str = "fedphd", sparse: bool = False,
-                      groups=None, lr: float = 2e-4, unroll: int = 8,
+                      groups=None, lr: float = 2e-4,
+                      unroll: Optional[int] = None,
                       prune_masks=None, mesh=None,
                       client_axis: str = "data", quant: str = "none"):
     """Build the jitted vectorized round program for ``method``.
@@ -263,9 +269,10 @@ def make_round_engine(cfg: ModelConfig, fl: FLConfig, *,
     (equivalence tests, benches, sweeps) no longer recompiles the round
     program.
 
-    ``mesh`` puts the stacked client axis on the device mesh: every
-    client-leading input (batches, valid, rngs, edge_idx, the gathered
-    Adam rows, per-client ctx entries) is laid over ``client_axis`` via
+    ``mesh`` puts the stacked client axis on the device mesh: the
+    caller stages batches and valid with :func:`stage_clients`, and the
+    engine lays every other client-leading input (rngs, edge_idx, the
+    gathered Adam rows, per-client ctx entries) over ``client_axis`` via
     ``repro.launch.federated.shard_clients`` before dispatch, so jit's
     partitioner runs each device's client slice locally and the fused
     (E, C) aggregation einsum lowers to a cross-device all-reduce.
@@ -339,11 +346,24 @@ def _plain_round_engine(cfg, fl, method, lr, unroll, mesh, client_axis,
                                quant=quant)
 
 
+def stage_clients(tree, mesh=None, axis: str = "data"):
+    """Host -> device for client-leading operands (stacked batches,
+    valid masks): straight onto ``axis`` of ``mesh`` when there is one,
+    so a sharded round's data never stops on device 0 first; to the
+    default device otherwise."""
+    if mesh is None:
+        return jax.tree.map(jnp.asarray, tree)
+    from repro.launch.federated import shard_clients
+    return shard_clients(tree, mesh, axis)
+
+
 def _make_sharded_engine(engine, mesh, client_axis: str, ctx_axes):
     """Wrap a jitted round engine so every client-leading operand is
-    laid over ``client_axis`` before dispatch.  Inputs whose leading
-    dim doesn't divide the axis (shard_clients warns once) and the
-    small replicated operands (edge stack, (E, C) weight rows) pass
+    laid over ``client_axis`` before dispatch.  The round data
+    (``batches``, ``valid``) arrives laid out already: the trainers
+    stage it host -> mesh (:func:`stage_clients`).  Inputs whose
+    leading dim doesn't divide the axis (shard_clients warns once) and
+    the small replicated operands (edge stack, (E, C) weight rows) pass
     through — jit partitions the program from the sharded operands."""
     from repro.launch.federated import shard_clients
 
@@ -351,8 +371,7 @@ def _make_sharded_engine(engine, mesh, client_axis: str, ctx_axes):
                 ctx=None, opt_states=None, w_late=None, err=None,
                 masked=True, per_client_opt=False):
         put = lambda t: shard_clients(t, mesh, client_axis)
-        edge_idx, batches, valid, rngs = (
-            put(t) for t in (edge_idx, batches, valid, rngs))
+        edge_idx, rngs = put(edge_idx), put(rngs)
         if opt_states is not None:
             opt_states = put(opt_states)
         if err is not None:
@@ -367,7 +386,8 @@ def _make_sharded_engine(engine, mesh, client_axis: str, ctx_axes):
 
 
 def _build_round_engine(cfg: ModelConfig, fl: FLConfig, *, method: str,
-                        sparse: bool, groups, lr: float, unroll: int,
+                        sparse: bool, groups, lr: float,
+                        unroll: Optional[int],
                         prune_masks=None, mesh=None,
                         client_axis: str = "data", quant: str = "none"):
     loss_fn = make_loss_fn(cfg, fl, method=method, sparse=sparse,

@@ -20,15 +20,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(nmask_ref, kmask_ref, x_ref, w_ref, o_ref, acc_ref, *,
+def _kernel(nflags_ref, kflags_ref, x_ref, w_ref, o_ref, acc_ref, *,
             n_kblocks: int):
+    j = pl.program_id(1)
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    active = (nmask_ref[0] != 0) & (kmask_ref[0] != 0)
+    # per-tile flags live in SMEM (scalar prefetch): Mosaic has no
+    # legal VMEM block for a (1,) slice of a flag vector
+    active = (nflags_ref[j] != 0) & (kflags_ref[kb] != 0)
 
     @pl.when(active)
     def _compute():
@@ -42,11 +45,12 @@ def _kernel(nmask_ref, kmask_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
 def block_masked_matmul(x, w, col_mask, row_mask, *, bm: int = 128,
                         bk: int = 128, bn: int = 128,
-                        interpret: bool = False):
+                        interpret: bool):
     """x: (M, K); w: (K, N); col_mask: (N,) 0/1; row_mask: (K,) 0/1.
 
-    Masks are reduced to per-block "any nonzero" flags; tiles whose flag
-    is 0 are skipped entirely (their VMEM tiles never reach the MXU).
+    Masks are reduced to per-block "any nonzero" flags, prefetched into
+    SMEM; tiles whose flag is 0 are skipped entirely (their VMEM tiles
+    never reach the MXU).
     """
     M, K = x.shape
     K2, N = w.shape
@@ -63,17 +67,19 @@ def block_masked_matmul(x, w, col_mask, row_mask, *, bm: int = 128,
           * row_mask[:, None].astype(w.dtype))
 
     kernel = functools.partial(_kernel, n_kblocks=nkb)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                               # nflags, kflags
         grid=(nmb, nnb, nkb),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j, k: (j,)),          # nflags
-            pl.BlockSpec((1,), lambda i, j, k: (k,)),          # kflags
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),    # x
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),    # w
+            pl.BlockSpec((bm, bk), lambda i, j, k, nf, kf: (i, k)),   # x
+            pl.BlockSpec((bk, bn), lambda i, j, k, nf, kf: (k, j)),   # w
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, nf, kf: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
     )(nflags, kflags, x, wm)

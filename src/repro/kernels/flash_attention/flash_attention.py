@@ -73,7 +73,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          bq: int = 128, bk: int = 128,
-                         interpret: bool = False):
+                         interpret: bool):
     """q: (BH, Sq, hd); k, v: (BH, Skv, hd)."""
     BH, Sq, hd = q.shape
     Skv = k.shape[1]

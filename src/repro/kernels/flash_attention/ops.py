@@ -1,4 +1,4 @@
-"""Jitted wrapper for the flash-attention kernel.
+"""Wrapper for the flash-attention kernel.
 
 Accepts the model-layout (B, S, H, hd) tensors (GQA pre-expanded by the
 caller) and dispatches to the Pallas kernel; non-tile-aligned shapes fall
@@ -6,19 +6,15 @@ back to the oracle.
 """
 from __future__ import annotations
 
-from functools import partial
-
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import note_route
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                   "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    window: int = 0, bq: int = 128, bk: int = 128):
     """q: (B, Sq, Hq, hd); k, v: (B, Skv, Hkv, hd) -> (B, Sq, Hq, hd)."""
     B, Sq, Hq, hd = q.shape
     Hkv = k.shape[2]
@@ -29,7 +25,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * Hq, -1, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hq, -1, hd)
-    if Sq % bq or kf.shape[1] % bk:
+    fallback = bool(Sq % bq or kf.shape[1] % bk)
+    note_route("flash_attention", qf.shape, fallback=fallback,
+               interpret=interpret)
+    if fallback:
         out = flash_attention_ref(qf, kf, vf, causal=causal, window=window)
     else:
         out = flash_attention_bhsd(qf, kf, vf, causal=causal, window=window,

@@ -10,7 +10,7 @@ from repro.kernels.rglru_scan.ref import rglru_scan_ref
 
 
 @partial(jax.jit, static_argnames=("bs", "interpret"))
-def linear_recurrence(a, b, *, bs: int = 256, interpret: bool = True):
+def linear_recurrence(a, b, *, interpret: bool, bs: int = 256):
     """h_t = a_t h_{t-1} + b_t, blocked-VMEM kernel with jnp fallback."""
     B, S, W = a.shape
     if S % bs:

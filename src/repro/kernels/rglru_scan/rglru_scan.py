@@ -38,7 +38,7 @@ def _kernel(a_ref, b_ref, o_ref, h_ref, *, bs: int):
     h_ref[0] = h
 
 
-def rglru_scan(a, b, *, bs: int = 256, interpret: bool = False):
+def rglru_scan(a, b, *, interpret: bool, bs: int = 256):
     """a, b: (B, S, W) -> h: (B, S, W) with h_t = a_t h_{t-1} + b_t."""
     B, S, W = a.shape
     assert S % bs == 0

@@ -201,7 +201,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             # temporaries a TPU build fuses — the analytic model below is
             # the fits-verdict (EXPERIMENTS.md caveats).
             "analytic_bytes_per_device": analytic_mem,
-            "hbm_limit": hw.HBM_BYTES,
+            "hbm_limit": hw.peaks(hw.V5E).hbm_bytes,
         },
         "xla_cost_analysis": {k: cost.get(k) for k in
                               ("flops", "bytes accessed", "transcendentals")
@@ -214,7 +214,8 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         mem_gb = analytic_mem / 2**30
         xla_gb = rec["memory"]["peak_bytes_per_device"] / 2**30 \
             if rec["memory"]["peak_bytes_per_device"] else float("nan")
-        fits = "FITS" if mem_gb < hw.HBM_BYTES / 2**30 else "OVER-HBM"
+        fits = "FITS" if mem_gb < hw.peaks(hw.V5E).hbm_bytes / 2**30 \
+            else "OVER-HBM"
         print(f"[{arch} x {shape_name} x {rec['mesh']}] "
               f"lower {t_lower:.1f}s compile {t_compile:.1f}s | "
               f"analytic {mem_gb:.2f} GiB/chip ({fits}; xla-cpu {xla_gb:.1f}) | "

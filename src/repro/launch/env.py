@@ -9,8 +9,8 @@ single CPU process presents N devices to jax.
 
 All of these are READ AT PROCESS START (LD_PRELOAD by the dynamic
 linker, XLA_FLAGS at backend initialization), which is why this module
-deliberately never imports jax: it must be importable — and
-``apply()``-able — before the first jax import.  Three entry points:
+deliberately never imports jax at module level: it must be importable
+— and ``apply()``-able — before the first jax import.  Entry points:
 
 ``host_env``    — build the env-var overlay (pure; no side effects).
 ``apply``       — install the overlay into ``os.environ`` for THIS
@@ -18,12 +18,17 @@ deliberately never imports jax: it must be importable — and
 ``child_env``   — a minimal sanitized environment for a subprocess
                   (the tests' 8-fake-device pattern) or a rendered
                   cluster Job container.
+``enable_compile_cache`` — turn on JAX's persistent compilation cache
+                  (``$JAX_COMPILATION_CACHE_DIR``, else one fixed
+                  directory in the checkout); the CLIs call it under
+                  their ``__main__`` guard, tests never do.
 
 CI's mesh-smoke job and tests/test_mesh_engine.py drive the sharded
 round engine through ``child_env(devices=8)`` + an in-child ``apply()``.
 """
 from __future__ import annotations
 
+import importlib.util
 import os
 import re
 import sys
@@ -41,6 +46,12 @@ TCMALLOC_CANDIDATES = (
 # 60 GB: parameter stacks of a few GB must not trip tcmalloc's
 # large-alloc stderr report on every round
 TCMALLOC_REPORT_THRESHOLD = "60000000000"
+
+# the persistent compile cache's home when $JAX_COMPILATION_CACHE_DIR is
+# unset: fixed (a moving directory never hits) and gitignored
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 _DEVCOUNT_FLAG = re.compile(r"--xla_force_host_platform_device_count=\d+")
 
@@ -97,6 +108,17 @@ def host_env(devices: Optional[int] = None, *,
     return env
 
 
+def tpu_expected() -> bool:
+    """Would a fresh JAX process started from this environment take the
+    TPU?  Decided without initializing a backend — doing that would
+    claim the chip: ``$JAX_PLATFORMS`` names ``tpu``, or is unset and
+    libtpu is installed."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms:
+        return "tpu" in platforms.split(",")
+    return importlib.util.find_spec("libtpu") is not None
+
+
 def _jax_backend_live() -> bool:
     """Has a jax backend already initialized (and thus consumed
     XLA_FLAGS)?  Merely having imported jax is fine — flags are read at
@@ -148,3 +170,18 @@ def child_env(devices: Optional[int] = None, *,
     }
     return host_env(devices, tcmalloc=tcmalloc, platform=platform,
                     base=base)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first
+    compile; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set.
+    Otherwise the cache goes to :data:`DEFAULT_COMPILE_CACHE`, exported
+    through the same variable so spawned workers share it.
+    """
+    path = os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                                 DEFAULT_COMPILE_CACHE)
+    import jax
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

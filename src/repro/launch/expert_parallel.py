@@ -28,11 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
-
-if hasattr(jax, "shard_map"):           # public API, jax >= 0.6
-    _shard_map = jax.shard_map
-else:                                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 from repro.models.common import activation_fn
 from repro.models.ffn import apply_ffn
 from repro.models.moe import load_balance_loss, router_topk
@@ -128,7 +123,7 @@ def apply_moe_ep(p, x, moe: MoEConfig, *, mesh, ep_axes: Tuple[str, ...],
             * w_l.reshape(-1, 1).astype(xt_l.dtype)
         return jnp.sum(gathered.reshape(T_loc, k, d), axis=1)
 
-    out = _shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(tok_spec, None), P(tok_spec, None), P(tok_spec, None),
                   P(ep_spec, None, None), P(ep_spec, None, None),

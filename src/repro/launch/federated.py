@@ -21,11 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):           # public API, jax >= 0.6
-    _shard_map = jax.shard_map
-else:                                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _sh_weight(n, mu, a: float, b: float):
     return jnp.maximum(n + a * mu + b, 0.0)
@@ -113,7 +108,7 @@ def hierarchical_aggregate(params, n_samples, sh_score, *, mesh,
         P(*((spec_axes,) + (None,) * (leaf.ndim - 1))) if leaf.ndim else P()
         for leaf in leaves)
     # client replicas are stacked on a leading axis sharded over the tiers
-    out = _shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(leaf_specs, P(spec_axes), P(spec_axes)),
         out_specs=leaf_specs,
@@ -130,4 +125,5 @@ def federated_round_cost(model_bytes: int, *, n_pods: int = 2,
     ici = 2 * model_bytes * (clients_per_pod - 1) / clients_per_pod
     dcn = 2 * model_bytes * (n_pods - 1) / n_pods if cloud_round else 0.0
     return {"ici_bytes": ici, "dcn_bytes": dcn,
-            "ici_s": ici / hw.ICI_BW, "dcn_s": dcn / hw.DCN_BW}
+            "ici_s": ici / hw.peaks(hw.V5E).ici_bw,
+            "dcn_s": dcn / hw.DCN_BW}

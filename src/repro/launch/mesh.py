@@ -16,12 +16,25 @@ from typing import Mapping, Optional
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes.
+
+    The sharding code in this package (``shard_map`` bodies, GSPMD
+    partitioning of the round engine, ``with_sharding_constraint``) was
+    written for auto-sharded axes; ``jax.make_mesh`` now defaults to
+    ``Explicit`` ones, under which e.g. the expert-parallel output
+    reshape is a sharding type error."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -38,7 +51,7 @@ def make_host_mesh(model_axis: int = 1):
             f"model_axis={model_axis} does not divide the {n} available "
             f"device(s); an uneven split would silently drop "
             f"{n % model_axis if model_axis >= 1 else n} of them")
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return make_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def make_spec_mesh(axes: Optional[Mapping[str, int]]):

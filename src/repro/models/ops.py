@@ -8,10 +8,12 @@ sparse-phase masked matmul — routes through ONE of three backends:
               the exact expressions the round engine compiled before
               this layer existed);
   ``pallas``  the Pallas TPU kernels under :mod:`repro.kernels`
-              (``interpret=True`` off-TPU, so CPU CI exercises the real
+              (compiled on TPU, interpreted elsewhere — decided only by
+              :func:`pallas_interpret` — so CPU CI exercises the real
               BlockSpec tiling), with the pure-jnp oracle as fallback
-              on non-tile-aligned shapes — the same contract the kernel
-              ``ops.py`` wrappers already enforce;
+              on non-tile-aligned shapes — the kernel ``ops.py``
+              wrappers gate that and report every decision through
+              :func:`repro.kernels.note_route`;
   ``ref``     the kernels' pure-jnp oracles (``ref.py``) — the
               slow-but-obvious reference the other two are locked
               against (atol 1e-5, ``tests/test_ops_backends.py``).
@@ -85,7 +87,9 @@ def cast_floats(tree, dtype):
 
 
 def pallas_interpret() -> bool:
-    """Kernels run interpreted everywhere but real TPU."""
+    """The one place that picks interpret mode: kernels run compiled on
+    TPU and interpreted everywhere else (the kernel wrappers take the
+    answer from their caller and have no default)."""
     return jax.default_backend() != "tpu"
 
 

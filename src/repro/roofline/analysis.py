@@ -19,10 +19,11 @@ HLO text instead:
      split intra-pod (ICI) vs cross-pod (DCN) by replica-group span.
 
 All numbers are PER DEVICE (shapes in SPMD-partitioned HLO are
-per-device); the roofline terms divide by per-chip peak rates, so
-  compute_term    = flops_per_device / PEAK_FLOPS
-  memory_term     = hbm_bytes_per_device / HBM_BW
-  collective_term = ici_bytes / ICI_BW + dcn_bytes / DCN_BW.
+per-device); the roofline terms divide by the per-chip peak rates of
+the dry run's target, a v5e (:func:`repro.roofline.hw.peaks`), so
+  compute_term    = flops_per_device / flops_bf16
+  memory_term     = hbm_bytes_per_device / hbm_bw
+  collective_term = ici_bytes / ici_bw + dcn_bytes / DCN_BW.
 """
 from __future__ import annotations
 
@@ -221,15 +222,16 @@ class RooflineTerms:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / hw.PEAK_FLOPS_BF16
+        return self.flops / hw.peaks(hw.V5E).flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / hw.HBM_BW
+        return self.hbm_bytes / hw.peaks(hw.V5E).hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.ici_bytes / hw.ICI_BW + self.dcn_bytes / hw.DCN_BW
+        return (self.ici_bytes / hw.peaks(hw.V5E).ici_bw
+                + self.dcn_bytes / hw.DCN_BW)
 
     def dominant(self) -> str:
         terms = {"compute": self.compute_s, "memory": self.memory_s,

@@ -28,8 +28,9 @@ import jax, jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.models.moe import init_moe, apply_moe
 from repro.launch.expert_parallel import apply_moe_ep
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 moe = MoEConfig(num_experts=8, experts_per_token=2, d_expert=32,
                 capacity_factor=8.0)
 rng = jax.random.PRNGKey(0)
@@ -71,7 +72,8 @@ import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.federated import hierarchical_aggregate
-mesh = jax.make_mesh((2, 4), ('pod', 'data'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('pod', 'data'))
 params = {'w': jnp.arange(8, dtype=jnp.float32).reshape(8, 1)}
 n = jnp.full((8,), 10.0)
 mu = jnp.full((8,), 2.0)

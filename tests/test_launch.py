@@ -140,3 +140,53 @@ def test_roofline_parser_computations():
     comps = parse_hlo(_HLO)
     assert {"cond", "body", "main"} <= set(comps)
     assert comps["main"].is_entry
+
+
+def test_mesh_helpers_build_auto_axes():
+    """The sharding code was written for auto axes; jax.make_mesh now
+    defaults to explicit ones (the expert-parallel reshape breaks)."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_mesh, make_spec_mesh
+    for mesh in (make_host_mesh(), make_mesh((1,), ("data",)),
+                 make_spec_mesh({"data": 1})):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.roofline import hw
+    v5e = hw.peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no peak table"):
+        hw.peaks("cpu")
+
+
+@pytest.mark.parametrize("platforms,expected", [
+    ("cpu", False), ("tpu", True), ("cpu,tpu", True), ("cuda", False)])
+def test_tpu_expected_reads_platforms_without_a_backend(
+        monkeypatch, platforms, expected):
+    """The sweep parent decides its worker count from the environment
+    alone: asking JAX would initialize a backend and claim the chip."""
+    from repro.launch.env import tpu_expected
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    assert tpu_expected() is expected
+
+
+def test_compile_cache_location(tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR wins; otherwise one fixed, in-checkout
+    directory.  Run in children: tests never turn the cache on here."""
+    import os
+    import subprocess
+    import sys
+    from repro.launch.env import child_env
+    code = ("import jax; from repro.launch.env import enable_compile_cache;"
+            "p = enable_compile_cache();"
+            "print(p, jax.config.jax_compilation_cache_dir)")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = child_env(pythonpath=os.path.join(root, "src"))
+    got = subprocess.run([sys.executable, "-c", code], env=env, text=True,
+                         capture_output=True, timeout=120).stdout.split()
+    assert got == [os.path.join(root, ".jax_cache")] * 2
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    got = subprocess.run([sys.executable, "-c", code], env=env, text=True,
+                         capture_output=True, timeout=120).stdout.split()
+    assert got == [str(tmp_path)] * 2

@@ -268,3 +268,28 @@ def test_spec_threads_backend_to_trainer():
     assert loaded.backend == "ref"
     exp = Experiment(spec)
     assert exp.trainer.cfg.backend == "ref"
+
+
+def test_kernel_routes_are_recorded_and_fallbacks_warn(rng):
+    """Every kernel routing decision is visible: traced call sites are
+    recorded (interpret off the TPU, fallback off the tiles), and a
+    fallback from a compiled kernel warns instead of passing silently."""
+    import warnings
+    from repro.kernels import note_route, record_routes
+    x = jax.random.normal(rng, (2, 16, 16, 128))
+    p = {"w": jax.random.normal(rng, (3, 3, 128, 128)) * 0.05,
+         "b": jnp.zeros((128,))}
+    with record_routes() as routes:
+        jax.jit(lambda p_, x_: ops.conv(p_, x_, backend="pallas"))(p, x)
+        ops.dense({"w": jnp.ones((27, 128)), "b": jnp.zeros((128,))},
+                  jnp.ones((32, 27)), backend="pallas")
+    assert [(r.kernel, r.path, r.shape) for r in routes] == [
+        ("block_masked_matmul", "interpret", (512, 1152, 128)),
+        ("block_masked_matmul", "fallback", (32, 27, 128))]
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        note_route("block_masked_matmul", (32, 27, 128), fallback=True,
+                   interpret=False)
+        note_route("block_masked_matmul", (32, 27, 128), fallback=True,
+                   interpret=True)
+    assert len(caught) == 1 and "not tile-aligned" in str(caught[0].message)
