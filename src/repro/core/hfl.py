@@ -273,6 +273,7 @@ class FedPhD:
         (and buffer) fp32.
         """
         fl = self.fl
+        obs = self._obs
         up_q, up_f, down = wire
         step_fn = self.step_sparse if sparse_round else self.step_plain
         round_losses: List[float] = []
@@ -287,14 +288,17 @@ class FedPhD:
             n_arrived = 0
             for cid in cids:
                 cl = self.clients[cid]
-                self.rng, sub = jax.random.split(self.rng)
-                budget = faults.budget_of(cid) if faults else None
-                opt_in = tree_gather(self._opt_stack, int(cid)) \
-                    if self.persistent_opt else self._opt_zero
-                p, opt_out, loss = run_local(step_fn, edge_model, cl,
-                                             epochs=fl.local_epochs, rng=sub,
-                                             opt_state=opt_in,
-                                             max_steps=budget)
+                with obs.span("client/train", round=r, client=int(cid),
+                              edge=e):
+                    self.rng, sub = jax.random.split(self.rng)
+                    budget = faults.budget_of(cid) if faults else None
+                    opt_in = tree_gather(self._opt_stack, int(cid)) \
+                        if self.persistent_opt else self._opt_zero
+                    p, opt_out, loss = run_local(step_fn, edge_model, cl,
+                                                 epochs=fl.local_epochs,
+                                                 rng=sub, opt_state=opt_in,
+                                                 max_steps=budget,
+                                                 tracer=obs)
                 completed = faults is None or faults.completed_of(cid)
                 late = faults is not None and faults.late_of(cid)
                 if self.persistent_opt and completed:
@@ -325,25 +329,27 @@ class FedPhD:
                     counts.append(cl.n_samples)
                     mus.append(sh_score(cl.q_n, self.q_u))
             if r % fl.edge_agg_every == 0:
-                if client_models:
-                    if self.aggregation == "sh":
-                        agg = aggregate_sh(client_models, counts, mus,
-                                           fl.sh_a, fl.sh_b)    # Eq. 23/24
+                with obs.span("round/edge_agg", round=r, edge=e,
+                              clients=len(client_models)):
+                    if client_models:
+                        if self.aggregation == "sh":
+                            agg = aggregate_sh(client_models, counts, mus,
+                                               fl.sh_a, fl.sh_b)  # Eq. 23/24
+                        else:
+                            agg = aggregate_fedavg(client_models, counts)
                     else:
-                        agg = aggregate_fedavg(client_models, counts)
-                else:
-                    # no client reported: the edge keeps its model
-                    agg = edge_model
-                if self.aggregation == "staleness":
-                    buf = self._late_buf.pop(e, None)
-                    if buf is not None:     # merge last round's stragglers
-                        agg = apply_late(agg, buf, self.fault.staleness
-                                         if self.fault else 0.0)
-                    if late_models:
-                        tot = max(sum(counts) + sum(late_counts), 1)
-                        w = [n / tot for n in late_counts]
-                        self._late_buf[e] = late_delta(late_models,
-                                                       edge_model, w)
+                        # no client reported: the edge keeps its model
+                        agg = edge_model
+                    if self.aggregation == "staleness":
+                        buf = self._late_buf.pop(e, None)
+                        if buf is not None:  # merge last round's stragglers
+                            agg = apply_late(agg, buf, self.fault.staleness
+                                             if self.fault else 0.0)
+                        if late_models:
+                            tot = max(sum(counts) + sum(late_counts), 1)
+                            w = [n / tot for n in late_counts]
+                            self._late_buf[e] = late_delta(late_models,
+                                                           edge_model, w)
                 if not hasattr(self, "_edge_models"):
                     self._edge_models = {}
                 self._edge_models[e] = agg
@@ -571,9 +577,6 @@ class FedPhD:
                      for c in sel_ids]
             faults = self._faults.draw_round(
                 sel_ids, steps, self.aggregation == "staleness")
-            if self._obs.enabled:
-                self._obs.event("fault/draw", round=r,
-                                **faults.summary())
 
         wire = self._wire_bytes()
         # lines 7-21: per-edge local training + edge aggregation
@@ -583,7 +586,8 @@ class FedPhD:
                     r, assignment, sparse_round, wire, faults)
         else:
             # the reference loop syncs per batch: host prep, compute and
-            # aggregation interleave, so it gets one dispatch span
+            # aggregation interleave, so one dispatch span holds the
+            # client/* and round/edge_agg spans
             with self._obs.span("round/dispatch", round=r):
                 round_losses, up_bytes, down_bytes, loss_mask = \
                     self._local_and_edge_sequential(

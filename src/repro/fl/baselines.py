@@ -285,7 +285,8 @@ class FlatTrainer:
                                              epochs=fl.local_epochs,
                                              rng=subs[i], ctx=ctx,
                                              opt_state=opt_in,
-                                             max_steps=budget)
+                                             max_steps=budget,
+                                             tracer=self._obs)
             losses.append(loss)
             if self.persistent_opt and completed:
                 self._opt_stack = tree_scatter(self._opt_stack, cid, opt_out)
@@ -583,9 +584,6 @@ class FlatTrainer:
                      for c in sel]
             faults = self._faults.draw_round(
                 sel, steps, self.aggregation == "staleness")
-            if self._obs.enabled:
-                self._obs.event("fault/draw", round=r,
-                                **faults.summary())
 
         if self._use_vectorized([self.clients[int(c)] for c in sel]):
             losses = self._round_vectorized(sel, subs, faults,

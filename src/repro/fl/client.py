@@ -23,6 +23,7 @@ from repro.core.pruning import depth_lambdas, omega
 from repro.data.pipeline import ClientData
 from repro.models import model
 from repro.models.ops import cast_floats, compute_dtype
+from repro.obs.trace import NULL_TRACER
 from repro.optim import adam_init, adam_update
 
 
@@ -155,7 +156,7 @@ class Client:
 
 def run_local(step_fn, params, client: Client, *, epochs: int, rng,
               ctx: Optional[Dict[str, Any]] = None, opt_state=None,
-              max_steps: Optional[int] = None):
+              max_steps: Optional[int] = None, tracer=NULL_TRACER):
     """Run E local epochs (Alg. 2).  Returns (params, opt_state, mean loss).
 
     ``max_steps`` caps the number of executed steps (fault injection:
@@ -164,6 +165,11 @@ def run_local(step_fn, params, client: Client, *, epochs: int, rng,
     in an untruncated round — keeping the sequential path in lockstep
     with the vectorized engine, whose ``stacked_epochs`` stacking
     always consumes whole epochs and truncates via the valid mask.
+
+    Each executed step is a ``client/step`` span of ``tracer`` (the
+    caller's; repro.obs) holding ``client/h2d`` (the batch's copy),
+    ``client/dispatch`` (the jitted step's call) and
+    ``client/loss_sync`` (the loss read back to the host).
     """
     if opt_state is None:
         opt_state = adam_init(params)
@@ -174,9 +180,14 @@ def run_local(step_fn, params, client: Client, *, epochs: int, rng,
         for batch in client.data.epoch():
             if max_steps is not None and executed >= max_steps:
                 continue                  # drain: shuffle RNG must advance
-            rng, sub = jax.random.split(rng)
-            jb = {k: jnp.asarray(v) for k, v in batch.items()}
-            params, opt_state, loss = step_fn(params, opt_state, jb, sub, ctx)
-            losses.append(float(loss))
+            with tracer.span("client/step"):
+                rng, sub = jax.random.split(rng)
+                with tracer.span("client/h2d"):
+                    jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                with tracer.span("client/dispatch"):
+                    params, opt_state, loss = step_fn(params, opt_state, jb,
+                                                      sub, ctx)
+                with tracer.span("client/loss_sync"):
+                    losses.append(float(loss))
             executed += 1
     return params, opt_state, float(np.mean(losses)) if losses else 0.0

@@ -168,18 +168,6 @@ class RoundFaults:
             "budgets": [int(b) for b in self.budget],
         }
 
-    def summary(self) -> dict:
-        """Compact counts for the obs ``fault/draw`` trace event (the
-        full per-client record stays in ``availability()``)."""
-        return {
-            "online": int(self.n_online),
-            "selected": int(len(self.sel_ids)),
-            "arrived": int(self.arrived.sum()),
-            "completed": int(self.completed.sum()),
-            "dropped": int(self.dropped.sum()),
-            "late": int(self.late.sum()),
-        }
-
 
 class FaultModel:
     """The seeded realization of a :class:`FaultSpec` over one client

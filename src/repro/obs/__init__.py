@@ -1,14 +1,16 @@
 """Observability layer: structured tracing for rounds, sweeps, serving.
 
-Zero-overhead-when-disabled by construction: every instrumented call
-site holds a tracer that is either a real :class:`~repro.obs.trace.
+Near-zero overhead when disabled by construction: every instrumented
+call site holds a tracer that is either a real :class:`~repro.obs.trace.
 Tracer` (JSON-lines span/counter/event emission) or the shared
-:data:`~repro.obs.trace.NULL_TRACER` whose methods are no-ops and whose
-``span()`` returns one reusable no-op context manager.  Tracing is
-host-side wall-clock only — it never touches RNG streams, device
-buffers, or numerics — so ``obs`` disabled (the default) is a bitwise
-no-op on every engine x backend x precision leg, and *enabled* changes
-timing visibility, not trajectories (asserted by tests/test_obs.py).
+:data:`~repro.obs.trace.NULL_TRACER` whose methods write nothing and
+whose ``span()`` is a bare ``jax.profiler.TraceAnnotation``: both
+tracers' spans reach a profiler trace being recorded, on the device's
+clock.  Tracing is host-side only — it never touches RNG streams,
+device buffers, or numerics — so ``obs`` disabled (the default) is a
+bitwise no-op on every engine x backend x precision leg, and *enabled*
+changes timing visibility, not trajectories (asserted by
+tests/test_obs.py).
 
 Enable per-run via ``ExperimentSpec(obs=ObsSpec(enabled=True))``, the
 CLI ``--trace`` flag, or ``$FEDPHD_OBS=1`` (resolution contract:

@@ -1,4 +1,13 @@
-"""The trace emitters: Tracer (JSON-lines) and the no-op NullTracer.
+"""The span API: Tracer (JSON-lines) and NullTracer (writes nothing).
+
+Every span (``Tracer.span`` and ``NullTracer.span``) also enters a
+``jax.profiler.TraceAnnotation(name, **attrs)``, so while a profile is
+being recorded (``jax.profiler.trace(dir)``, or the benchmark's
+``--trace 1``) the span lands on the profiler's host plane, on the same
+clock as the device's operations, whether or not the JSON-lines sink
+is on.  With no profile running an annotation is a liveness check and
+its attributes are not encoded.  ``record_span`` (endpoints measured
+after the fact) reaches the JSON-lines sink only.
 
 Schema (stable; tests/test_obs.py pins the golden keys) — one JSON
 object per line of ``trace.jsonl``:
@@ -18,21 +27,28 @@ object per line of ``trace.jsonl``:
 Span names in use: ``round/host_prep``, ``round/h2d``,
 ``round/dispatch``, ``round/loss_sync``, ``round/edge_agg``,
 ``round/cloud_agg``, ``round/prune`` (trainers; ``attrs.round`` keys
-the round), ``serve/tick`` (DiffusionServer).  Counter names:
+the round); on the sequential path, inside ``round/dispatch``:
+``client/train`` (one client's local training; ``round``, ``client``,
+``edge``) > ``client/step`` > ``client/h2d``, ``client/dispatch``,
+``client/loss_sync`` (fl/client.py ``run_local``), and one
+``round/edge_agg`` per edge (``round``, ``edge``, ``clients``);
+``serve/tick`` (DiffusionServer, ``record_span``).  Counter names:
 ``compile/<fn>`` (jit-cache growth; ``attrs.unexpected`` > 0 flags a
 recompile beyond the expected first compile).  Event names:
-``fault/draw`` (availability summary), ``serve/fault``.
+``serve/fault``.
 
-Everything here is host-side bookkeeping: no jax imports, no device
-syncs, no RNG.  The NULL_TRACER singleton makes the disabled path a
-handful of attribute lookups and a no-op context manager — cheap
-enough to leave the instrumentation permanently in the hot loops.
+Everything here is host-side bookkeeping: no device syncs, no RNG.
+The NULL_TRACER singleton makes the disabled path one annotation
+object per span — cheap enough to leave the instrumentation
+permanently in the hot loops.
 """
 from __future__ import annotations
 
 import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 SCHEMA_VERSION = 1
 
@@ -43,22 +59,9 @@ COUNTER_KEYS = ("ev", "name", "t", "value", "attrs")
 META_KEYS = ("ev", "schema", "wall_time", "attrs")
 
 
-class _NullSpan:
-    """Reusable no-op context manager (one shared instance)."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """The disabled tracer: every method is a no-op.
+    """The disabled tracer: nothing is written; ``span`` is the bare
+    profiler annotation, so spans still reach a profile being recorded.
 
     Shared singleton (:data:`NULL_TRACER`); trainers hold it when obs
     is off so call sites never branch on "is tracing on?".
@@ -67,7 +70,7 @@ class NullTracer:
     compile_tracking = False
 
     def span(self, name, **attrs):
-        return _NULL_SPAN
+        return TraceAnnotation(name, **attrs)
 
     def record_span(self, name, t0, t1, **attrs):
         pass
@@ -89,19 +92,22 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, tracer, name, attrs):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
+        self._ann = TraceAnnotation(name, **attrs)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self._tracer._emit({"ev": "span", "name": self._name,
                             "t0": self._t0, "t1": t1,
                             "dur_s": t1 - self._t0, "attrs": self._attrs})

@@ -4,6 +4,7 @@ engines, compile-tracker recompile detection, measured pipeline
 overlap, sweep scheduling spans surviving preemption + manifest
 reload, and the unified CLI metrics schema."""
 import dataclasses
+import glob
 import json
 import os
 
@@ -214,6 +215,72 @@ def test_disabled_obs_bitwise_noop_fedphd(engine, tmp_path):
     want = {"round/dispatch"} if engine == "sequential" \
         else {"round/dispatch", "round/loss_sync"}
     assert want <= names
+
+
+def _inside(child, parent):
+    return parent["t0"] <= child["t0"] and child["t1"] <= parent["t1"]
+
+
+def test_sequential_round_client_and_edge_spans(tmp_path):
+    """A traced sequential round: each client's training, each local
+    step with its batch copy, dispatch and loss sync, and each edge's
+    aggregation are spans, with their attributes, nested inside the
+    round's dispatch span."""
+    tracer = Tracer(str(tmp_path / "seq.jsonl"))
+    clients = make_clients()
+    tr = FedPhD(MICRO_UNET, FL, clients, rng_seed=0, engine="sequential",
+                prune=False, tracer=tracer)
+    tr.run_round(1)
+    tracer.close()
+    by = {}
+    for ln in read_lines(tracer.path):
+        if ln["ev"] == "span":
+            by.setdefault(ln["name"], []).append(ln)
+    steps = FL.local_epochs * clients[0].data.steps_per_epoch
+    (dispatch,) = by["round/dispatch"]
+    trains = by["client/train"]
+    assert sorted(t["attrs"]["client"] for t in trains) == [0, 1, 2, 3]
+    assert all(t["attrs"]["round"] == 1 and t["attrs"]["edge"] == 0
+               and _inside(t, dispatch) for t in trains)
+    assert len(by["client/step"]) == len(trains) * steps
+    for st in by["client/step"]:
+        assert any(_inside(st, t) for t in trains)
+    for name in ("client/h2d", "client/dispatch", "client/loss_sync"):
+        assert len(by[name]) == len(trains) * steps, name
+        for sp in by[name]:
+            assert any(_inside(sp, st) for st in by["client/step"]), name
+    (agg,) = by["round/edge_agg"]
+    assert agg["attrs"] == {"round": 1, "edge": 0, "clients": 4}
+    assert _inside(agg, dispatch)
+    assert all(t["t1"] <= agg["t0"] for t in trains)
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """Spans of both tracers land on a host plane of the profiler's
+    own trace, with their attributes, whether or not the JSON-lines
+    sink is on."""
+    from jax.profiler import ProfileData
+    tracer = Tracer(str(tmp_path / "p.jsonl"))
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with tracer.span("round/dispatch", round=4):
+            with NULL_TRACER.span("client/step"):
+                jax.numpy.ones(2).block_until_ready()
+    tracer.close()
+    (path,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile"
+                            / "*" / "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host[ev.name.split("#")[0]] = (dict(ev.stats), ev)
+    assert host["round/dispatch"][0]["round"] == 4
+    outer, inner = host["round/dispatch"][1], host["client/step"][1]
+    assert outer.start_ns <= inner.start_ns
+    assert (inner.start_ns + inner.duration_ns
+            <= outer.start_ns + outer.duration_ns)
+    assert [ln["name"] for ln in read_lines(tracer.path)
+            if ln["ev"] == "span"] == ["round/dispatch"]
 
 
 def test_disabled_obs_bitwise_noop_flat(tmp_path):
