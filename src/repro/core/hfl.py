@@ -23,7 +23,7 @@ from repro.configs.base import (FLConfig, ModelConfig, config_from_dict,
                                 config_to_dict)
 from repro.core.aggregation import (aggregate_fedavg, aggregate_sh,
                                     fedavg_weights, normalize_weights,
-                                    sh_weights)
+                                    sh_weights, weighted_sum)
 from repro.core.pruning import (build_groups, compact, l2_scores, make_masks,
                                 random_scores)
 from repro.core.selection import random_selection, select_edge
@@ -44,7 +44,7 @@ from repro.fl.faults import (FaultSpec, apply_late, late_delta,
 from repro.fl.record import RoundRecord, RunResult, evals_of
 from repro.models import model
 from repro.models.ops import resolve_backend, resolve_precision
-from repro.obs.compile_tracker import CompileTracker
+from repro.obs.compile_tracker import CompileTracker, cache_size
 from repro.obs.trace import NULL_TRACER
 from repro.optim import adam_init
 
@@ -330,7 +330,8 @@ class FedPhD:
                     mus.append(sh_score(cl.q_n, self.q_u))
             if r % fl.edge_agg_every == 0:
                 with obs.span("round/edge_agg", round=r, edge=e,
-                              clients=len(client_models)):
+                              clients=len(client_models),
+                              programs=cache_size(weighted_sum)):
                     if client_models:
                         if self.aggregation == "sh":
                             agg = aggregate_sh(client_models, counts, mus,
@@ -598,7 +599,8 @@ class FedPhD:
         # edge<->cloud tier ships fp32 uploads (quantization is the
         # client->edge uplink only) and compute-dtype broadcasts.
         if r % fl.cloud_agg_every == 0 and hasattr(self, "_edge_models"):
-            with self._obs.span("round/cloud_agg", round=r):
+            with self._obs.span("round/cloud_agg", round=r,
+                                programs=cache_size(weighted_sum)):
                 models, counts, mus = [], [], []
                 for e, m in self._edge_models.items():
                     models.append(m)

@@ -31,7 +31,9 @@ the round); on the sequential path, inside ``round/dispatch``:
 ``client/train`` (one client's local training; ``round``, ``client``,
 ``edge``) > ``client/step`` > ``client/h2d``, ``client/dispatch``,
 ``client/loss_sync`` (fl/client.py ``run_local``), and one
-``round/edge_agg`` per edge (``round``, ``edge``, ``clients``);
+``round/edge_agg`` per edge (``round``, ``edge``, ``clients``,
+``programs``: ``cache_size`` of the jitted ``weighted_sum`` as the
+aggregation begins, which ``round/cloud_agg`` carries too);
 ``serve/tick`` (DiffusionServer, ``record_span``).  Counter names:
 ``compile/<fn>`` (jit-cache growth; ``attrs.unexpected`` > 0 flags a
 recompile beyond the expected first compile).  Event names:

@@ -7,6 +7,7 @@ import dataclasses
 import glob
 import json
 import os
+import time
 
 import numpy as np
 import jax
@@ -14,6 +15,7 @@ import pytest
 
 from repro.configs import SMOKE_UNET, register_config
 from repro.configs.base import FLConfig
+from repro.core.aggregation import weighted_sum
 from repro.core.hfl import FedPhD
 from repro.data import ClientData, shards_per_client
 from repro.data.synthetic import DatasetSpec, make_dataset
@@ -225,17 +227,23 @@ def test_sequential_round_client_and_edge_spans(tmp_path):
     """A traced sequential round: each client's training, each local
     step with its batch copy, dispatch and loss sync, and each edge's
     aggregation are spans, with their attributes, nested inside the
-    round's dispatch span."""
+    round's dispatch span.  The aggregation spans carry the count of
+    compiled member-count programs, which a count already seen does
+    not raise."""
     tracer = Tracer(str(tmp_path / "seq.jsonl"))
     clients = make_clients()
     tr = FedPhD(MICRO_UNET, FL, clients, rng_seed=0, engine="sequential",
                 prune=False, tracer=tracer)
     tr.run_round(1)
+    programs = cache_size(weighted_sum)
+    split = time.perf_counter()
+    tr.run_round(2)
     tracer.close()
-    by = {}
+    by, by2 = {}, {}
     for ln in read_lines(tracer.path):
         if ln["ev"] == "span":
-            by.setdefault(ln["name"], []).append(ln)
+            (by if ln["t1"] < split else by2).setdefault(
+                ln["name"], []).append(ln)
     steps = FL.local_epochs * clients[0].data.steps_per_epoch
     (dispatch,) = by["round/dispatch"]
     trains = by["client/train"]
@@ -250,9 +258,18 @@ def test_sequential_round_client_and_edge_spans(tmp_path):
         for sp in by[name]:
             assert any(_inside(sp, st) for st in by["client/step"]), name
     (agg,) = by["round/edge_agg"]
-    assert agg["attrs"] == {"round": 1, "edge": 0, "clients": 4}
+    assert agg["attrs"] == {"round": 1, "edge": 0, "clients": 4,
+                            "programs": agg["attrs"]["programs"]}
+    assert agg["attrs"]["programs"] in (programs - 1, programs)
     assert _inside(agg, dispatch)
     assert all(t["t1"] <= agg["t0"] for t in trains)
+    # round 2 aggregates four clients again, then the one edge at the
+    # cloud: the repeated count compiled nothing
+    (agg2,) = by2["round/edge_agg"]
+    (cloud,) = by2["round/cloud_agg"]
+    assert agg2["attrs"]["clients"] == 4
+    assert agg2["attrs"]["programs"] == programs
+    assert cloud["attrs"] == {"round": 2, "programs": programs}
 
 
 def test_spans_reach_the_profiler_trace(tmp_path):

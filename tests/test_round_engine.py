@@ -7,11 +7,12 @@ import pytest
 
 from repro.configs import SMOKE_UNET
 from repro.configs.base import FLConfig
-from repro.core.aggregation import weighted_average
+from repro.core.aggregation import weighted_average, weighted_sum
 from repro.core.hfl import FedPhD
 from repro.data import SMOKE_DATA, ClientData, make_dataset, shards_per_client
 from repro.fl.client import Client
 from repro.fl.engine import uniform_batch_shape
+from repro.obs import cache_size
 
 
 def make_clients(n=4, batch_size=16):
@@ -112,21 +113,69 @@ def test_fedphd_persistent_opt_equivalence():
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5)
 
 
-def test_weighted_average_mixed_dtypes():
+MIXED = [{"w": jnp.asarray([1.0, 2.0], jnp.bfloat16),
+          "t": jnp.asarray(7, jnp.int32),
+          "f": np.asarray([0.5, 0.5], np.float32)},
+         {"w": jnp.asarray([3.0, 6.0], jnp.bfloat16),
+          "t": jnp.asarray(7, jnp.int32),
+          "f": np.asarray([1.5, 2.5], np.float32)},
+         {"w": jnp.asarray([-0.75, 5.5], jnp.bfloat16),
+          "t": jnp.asarray(12, jnp.int32),
+          "f": np.asarray([0.1, -3.0], np.float32)}]
+
+
+def eager_weighted_average(trees, weights):
+    """The per-leaf eager formula ``weighted_average`` replaced: stack
+    each leaf's members, one fp32 einsum, cast back (integers rounded)."""
+    w = np.asarray(weights, np.float64)
+    w = jnp.asarray((w / w.sum()).astype(np.float32))
+
+    def leaf(*xs):
+        stacked = jnp.stack(xs)
+        acc = jnp.einsum("n,n...->...", w, stacked.astype(jnp.float32))
+        if jnp.issubdtype(stacked.dtype, jnp.integer):
+            return jnp.round(acc).astype(stacked.dtype)
+        return acc.astype(stacked.dtype)
+    return jax.tree.map(leaf, *trees)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_weighted_average_mixed_dtypes(n):
     """fp32 accumulation for low-precision leaves; integer leaves (Adam
-    t) round-trip instead of truncating to zero."""
-    t1 = {"w": jnp.asarray([1.0, 2.0], jnp.bfloat16),
-          "t": jnp.asarray(7, jnp.int32),
-          "f": np.asarray([0.5, 0.5], np.float32)}
-    t2 = {"w": jnp.asarray([3.0, 6.0], jnp.bfloat16),
-          "t": jnp.asarray(7, jnp.int32),
-          "f": np.asarray([1.5, 2.5], np.float32)}
-    out = weighted_average([t1, t2], [1.0, 1.0])
-    assert out["w"].dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(out["w"], np.float32), [2.0, 4.0])
-    # identical step counters survive averaging exactly
-    assert out["t"].dtype == jnp.int32 and int(out["t"]) == 7
-    np.testing.assert_allclose(np.asarray(out["f"]), [1.0, 1.5])
-    # skewed integer weights round to nearest, not truncate
-    out2 = weighted_average([t1, t2], [1.0, 3.0])
-    assert int(out2["t"]) == 7
+    t) round-trip instead of truncating to zero; the same numbers as
+    the eager per-leaf formula for every member count."""
+    members = MIXED[:n]
+    for weights in ([1.0] * n, [1.0, 3.0, 2.0][:n]):
+        out = weighted_average(members, weights)
+        ref = eager_weighted_average(members, weights)
+        for key in ("w", "t", "f"):
+            assert out[key].dtype == ref[key].dtype == MIXED[0][key].dtype
+        assert int(out["t"]) == int(ref["t"])
+        for key in ("w", "f"):
+            np.testing.assert_allclose(np.asarray(out[key], np.float32),
+                                       np.asarray(ref[key], np.float32),
+                                       rtol=1e-6, atol=1e-7)
+    if n == 2:
+        out = weighted_average(members, [1.0, 1.0])
+        np.testing.assert_allclose(np.asarray(out["w"], np.float32),
+                                   [2.0, 4.0])
+        # identical step counters survive averaging exactly
+        assert int(out["t"]) == 7
+        np.testing.assert_allclose(np.asarray(out["f"]), [1.0, 1.5])
+        # skewed integer weights round to nearest, not truncate
+        assert int(weighted_average(members, [1.0, 3.0])["t"]) == 7
+
+
+def test_weighted_average_compiles_once_per_member_count():
+    """Other weights, other members of the same layout, or a tuple in
+    place of a list reuse the member-count program; a new count adds
+    exactly one."""
+    trees = [{"a": jnp.full((3,), float(i)),
+              "b": jnp.full((2, 7), i, jnp.bfloat16)} for i in range(4)]
+    weighted_average(trees[:2], [1.0, 2.0])
+    programs = cache_size(weighted_sum)
+    weighted_average(tuple(trees[:2]), [5.0, 0.5])
+    weighted_average(trees[2:], [1.0, 1.0])
+    assert cache_size(weighted_sum) == programs
+    weighted_average(trees[1:], [1.0, 1.0, 1.0])
+    assert cache_size(weighted_sum) == programs + 1
