@@ -127,6 +127,14 @@ class ModelConfig:
     # for the same reason as ``backend``: it is a static argument of
     # every compiled step/round program.
     precision: str = ""
+    # --- rematerialization ---------------------------------------------------
+    # "" keeps every activation the backward pass reads (no remat);
+    # "dots_with_no_batch_dims_saveable" runs the local step's loss
+    # (repro.fl.client.make_loss_fn) under jax.checkpoint with that
+    # policy: GEMM outputs are kept, the elementwise ops and the im2col
+    # patches are recomputed in the backward pass.  Changes memory and
+    # time, not the mathematics; a static argument of every step.
+    remat_policy: str = ""
 
     def __post_init__(self):
         if self.arch_type != "unet":

@@ -26,6 +26,11 @@ CIFAR10_UNET = ModelConfig(
 CELEBA_UNET = CIFAR10_UNET.replace(
     name="ddpm-unet-celeba",
     image_size=64,               # same net, 2x input size -> 4x MACs (Table IV)
+    # one client's batch-32 step holds 18.9 GiB of temporaries without
+    # remat (im2col patches kept for the weight gradients), more than a
+    # 16 GB v5e; recomputing the patches and elementwise ops brings it
+    # to about 6 GiB (PERF.md)
+    remat_policy="dots_with_no_batch_dims_saveable",
 )
 
 # Reduced variant for CPU smoke tests and the end-to-end example driver.

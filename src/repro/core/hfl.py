@@ -276,6 +276,7 @@ class FedPhD:
         obs = self._obs
         up_q, up_f, down = wire
         step_fn = self.step_sparse if sparse_round else self.step_plain
+        remat = self.cfg.remat_policy
         round_losses: List[float] = []
         loss_mask: List[bool] = []
         up_bytes, down_bytes = 0.0, 0.0
@@ -289,7 +290,7 @@ class FedPhD:
             for cid in cids:
                 cl = self.clients[cid]
                 with obs.span("client/train", round=r, client=int(cid),
-                              edge=e):
+                              edge=e, remat=remat):
                     self.rng, sub = jax.random.split(self.rng)
                     budget = faults.budget_of(cid) if faults else None
                     opt_in = tree_gather(self._opt_stack, int(cid)) \
@@ -298,7 +299,7 @@ class FedPhD:
                                                  epochs=fl.local_epochs,
                                                  rng=sub, opt_state=opt_in,
                                                  max_steps=budget,
-                                                 tracer=obs)
+                                                 tracer=obs, remat=remat)
                 completed = faults is None or faults.completed_of(cid)
                 late = faults is not None and faults.late_of(cid)
                 if self.persistent_opt and completed:

@@ -286,7 +286,8 @@ class FlatTrainer:
                                              rng=subs[i], ctx=ctx,
                                              opt_state=opt_in,
                                              max_steps=budget,
-                                             tracer=self._obs)
+                                             tracer=self._obs,
+                                             remat=cfg.remat_policy)
             losses.append(loss)
             if self.persistent_opt and completed:
                 self._opt_stack = tree_scatter(self._opt_stack, cid, opt_out)

@@ -23,6 +23,7 @@ from repro.core.pruning import depth_lambdas, omega
 from repro.data.pipeline import ClientData
 from repro.models import model
 from repro.models.ops import cast_floats, compute_dtype
+from repro.obs.compile_tracker import cache_size
 from repro.obs.trace import NULL_TRACER
 from repro.optim import adam_init, adam_update
 
@@ -82,6 +83,9 @@ def make_loss_fn(cfg: ModelConfig, fl: FLConfig, *, method: str = "fedphd",
     ``make_train_one``) compute forward+backward in bf16 while
     ``value_and_grad`` transposes the ``astype`` back to fp32 grads;
     the params the optimizer sees remain the fp32 master weights.
+
+    ``cfg.remat_policy`` (``rematerialize``) wraps the whole loss in
+    ``jax.checkpoint``, once, for both consumers alike.
     """
     lambdas = depth_lambdas(groups, fl.lambda0) if (sparse and groups) else None
     dt = compute_dtype(cfg.precision)
@@ -106,7 +110,34 @@ def make_loss_fn(cfg: ModelConfig, fl: FLConfig, *, method: str = "fedphd",
             loss = loss + fl.moon_mu * con
         return loss
 
-    return loss_fn
+    return rematerialize(loss_fn, cfg.remat_policy)
+
+
+# the policies ``ModelConfig.remat_policy`` may name
+REMAT_POLICIES = {
+    "dots_with_no_batch_dims_saveable":
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+}
+
+
+def rematerialize(loss_fn, policy: str):
+    """``loss_fn`` under ``jax.checkpoint`` with the named policy (see
+    ``ModelConfig.remat_policy``), inside a ``remat`` named scope so the
+    ops it recomputes in the backward pass carry the name in their HLO
+    op names; ``loss_fn`` itself where ``policy`` is "", so the step
+    traces as it does without the setting."""
+    if not policy:
+        return loss_fn
+    if policy not in REMAT_POLICIES:
+        raise ValueError(f"unknown remat_policy {policy!r}; expected \"\" "
+                         f"or one of {sorted(REMAT_POLICIES)}")
+    ckpt = jax.checkpoint(loss_fn, policy=REMAT_POLICIES[policy])
+
+    def remat_loss_fn(params, batch, rng, ctx):
+        with jax.named_scope("remat"):
+            return ckpt(params, batch, rng, ctx)
+
+    return remat_loss_fn
 
 
 def scaffold_correction(grads, ctx):
@@ -138,6 +169,16 @@ def make_local_step(cfg: ModelConfig, fl: FLConfig, *, method: str = "fedphd",
     return step
 
 
+def temp_bytes(step_fn, *args) -> Optional[int]:
+    """Temporary bytes of the jitted ``step_fn`` compiled for ``args``
+    (``memory_analysis()``: what the program allocates beyond its
+    arguments and outputs); None where the backend gives no analysis.
+    After a call with the same arguments this reads jit's own caches:
+    nothing is traced or compiled again."""
+    ma = step_fn.lower(*args).compile().memory_analysis()
+    return None if ma is None else int(ma.temp_size_in_bytes)
+
+
 @dataclasses.dataclass
 class Client:
     """One federated client: local data + label distribution q_n."""
@@ -156,7 +197,8 @@ class Client:
 
 def run_local(step_fn, params, client: Client, *, epochs: int, rng,
               ctx: Optional[Dict[str, Any]] = None, opt_state=None,
-              max_steps: Optional[int] = None, tracer=NULL_TRACER):
+              max_steps: Optional[int] = None, tracer=NULL_TRACER,
+              remat: str = ""):
     """Run E local epochs (Alg. 2).  Returns (params, opt_state, mean loss).
 
     ``max_steps`` caps the number of executed steps (fault injection:
@@ -167,26 +209,35 @@ def run_local(step_fn, params, client: Client, *, epochs: int, rng,
     always consumes whole epochs and truncates via the valid mask.
 
     Each executed step is a ``client/step`` span of ``tracer`` (the
-    caller's; repro.obs) holding ``client/h2d`` (the batch's copy),
+    caller's; repro.obs; its ``remat`` attribute is the step's
+    ``ModelConfig.remat_policy``) holding ``client/h2d`` (the batch's copy),
     ``client/dispatch`` (the jitted step's call) and
-    ``client/loss_sync`` (the loss read back to the host).
+    ``client/loss_sync`` (the loss read back to the host).  With the
+    tracer enabled, a call that compiles ``step_fn`` anew (its jit cache
+    grows) is followed by one ``step/temp_bytes`` counter (``remat``):
+    the compiled step's temporary bytes (``temp_bytes``).
     """
     if opt_state is None:
         opt_state = adam_init(params)
     ctx = ctx or {}
     losses = []
     executed = 0
+    compiled = cache_size(step_fn) if tracer.enabled else None
     for _ in range(epochs):
         for batch in client.data.epoch():
             if max_steps is not None and executed >= max_steps:
                 continue                  # drain: shuffle RNG must advance
-            with tracer.span("client/step"):
+            with tracer.span("client/step", remat=remat):
                 rng, sub = jax.random.split(rng)
                 with tracer.span("client/h2d"):
                     jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                args = (params, opt_state, jb, sub, ctx)
                 with tracer.span("client/dispatch"):
-                    params, opt_state, loss = step_fn(params, opt_state, jb,
-                                                      sub, ctx)
+                    params, opt_state, loss = step_fn(*args)
+                if compiled is not None and cache_size(step_fn) > compiled:
+                    compiled = cache_size(step_fn)
+                    tracer.counter("step/temp_bytes",
+                                   temp_bytes(step_fn, *args), remat=remat)
                 with tracer.span("client/loss_sync"):
                     losses.append(float(loss))
             executed += 1

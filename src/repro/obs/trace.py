@@ -1,9 +1,10 @@
 """The span API: Tracer (JSON-lines) and NullTracer (writes nothing).
 
-Every span (``Tracer.span`` and ``NullTracer.span``) also enters a
-``jax.profiler.TraceAnnotation(name, **attrs)``, so while a profile is
+Every span (``Tracer.span`` and ``NullTracer.span``) and every
+``Tracer.counter`` also enters a ``jax.profiler.TraceAnnotation(name,
+**attrs)`` (a counter's carries ``value`` too), so while a profile is
 being recorded (``jax.profiler.trace(dir)``, or the benchmark's
-``--trace 1``) the span lands on the profiler's host plane, on the same
+``--trace 1``) it lands on the profiler's host plane, on the same
 clock as the device's operations, whether or not the JSON-lines sink
 is on.  With no profile running an annotation is a liveness check and
 its attributes are not encoded.  ``record_span`` (endpoints measured
@@ -29,14 +30,18 @@ Span names in use: ``round/host_prep``, ``round/h2d``,
 ``round/cloud_agg``, ``round/prune`` (trainers; ``attrs.round`` keys
 the round); on the sequential path, inside ``round/dispatch``:
 ``client/train`` (one client's local training; ``round``, ``client``,
-``edge``) > ``client/step`` > ``client/h2d``, ``client/dispatch``,
+``edge``, ``remat``) > ``client/step`` (``remat``: the step's
+``ModelConfig.remat_policy``) > ``client/h2d``, ``client/dispatch``,
 ``client/loss_sync`` (fl/client.py ``run_local``), and one
 ``round/edge_agg`` per edge (``round``, ``edge``, ``clients``,
 ``programs``: ``cache_size`` of the jitted ``weighted_sum`` as the
 aggregation begins, which ``round/cloud_agg`` carries too);
 ``serve/tick`` (DiffusionServer, ``record_span``).  Counter names:
 ``compile/<fn>`` (jit-cache growth; ``attrs.unexpected`` > 0 flags a
-recompile beyond the expected first compile).  Event names:
+recompile beyond the expected first compile); ``step/temp_bytes``
+(fl/client.py ``run_local``, after each call that compiled the local
+step: the compiled step's temporary bytes from ``memory_analysis()``;
+``remat``).  Event names:
 ``serve/fault``.
 
 Everything here is host-side bookkeeping: no device syncs, no RNG.
@@ -146,6 +151,8 @@ class Tracer:
                     "t": time.perf_counter(), "attrs": attrs})
 
     def counter(self, name: str, value, **attrs):
+        with TraceAnnotation(name, value=value, **attrs):
+            pass
         self._emit({"ev": "counter", "name": name,
                     "t": time.perf_counter(), "value": value,
                     "attrs": attrs})

@@ -29,12 +29,13 @@ from repro.experiment.resolve import (BACKENDS, KNOBS, knob_source,
                                       resolve_engine, resolve_knob,
                                       resolve_obs, validate_env)
 from repro.fl.baselines import FlatTrainer
-from repro.fl.client import Client
+from repro.fl.client import Client, temp_bytes
 from repro.obs.compile_tracker import CompileTracker, cache_size
 from repro.obs.metrics import summarize_trace
 from repro.obs.spec import ObsSpec
 from repro.obs.trace import (COUNTER_KEYS, EVENT_KEYS, META_KEYS, NULL_TRACER,
                              SCHEMA_VERSION, SPAN_KEYS, Tracer, make_tracer)
+from repro.optim import adam_init
 
 MICRO_UNET = SMOKE_UNET.replace(name="ddpm-unet-tiny-obs", image_size=8,
                                 base_channels=8, channel_mults=(1,),
@@ -227,13 +228,16 @@ def test_sequential_round_client_and_edge_spans(tmp_path):
     """A traced sequential round: each client's training, each local
     step with its batch copy, dispatch and loss sync, and each edge's
     aggregation are spans, with their attributes, nested inside the
-    round's dispatch span.  The aggregation spans carry the count of
+    round's dispatch span.  The client spans carry the step's
+    rematerialization setting, and the compiled step's temporary bytes
+    are one counter.  The aggregation spans carry the count of
     compiled member-count programs, which a count already seen does
     not raise."""
     tracer = Tracer(str(tmp_path / "seq.jsonl"))
     clients = make_clients()
-    tr = FedPhD(MICRO_UNET, FL, clients, rng_seed=0, engine="sequential",
-                prune=False, tracer=tracer)
+    remat = "dots_with_no_batch_dims_saveable"
+    tr = FedPhD(MICRO_UNET.replace(remat_policy=remat), FL, clients,
+                rng_seed=0, engine="sequential", prune=False, tracer=tracer)
     tr.run_round(1)
     programs = cache_size(weighted_sum)
     split = time.perf_counter()
@@ -249,10 +253,21 @@ def test_sequential_round_client_and_edge_spans(tmp_path):
     trains = by["client/train"]
     assert sorted(t["attrs"]["client"] for t in trains) == [0, 1, 2, 3]
     assert all(t["attrs"]["round"] == 1 and t["attrs"]["edge"] == 0
+               and t["attrs"]["remat"] == remat
                and _inside(t, dispatch) for t in trains)
     assert len(by["client/step"]) == len(trains) * steps
     for st in by["client/step"]:
         assert any(_inside(st, t) for t in trains)
+        assert st["attrs"] == {"remat": remat}
+    (temp,) = [ln for ln in read_lines(tracer.path)
+               if ln["ev"] == "counter" and ln["name"] == "step/temp_bytes"]
+    assert temp["attrs"] == {"remat": remat}
+    batch = {k: jax.numpy.asarray(v)
+             for k, v in next(iter(clients[0].data.epoch())).items()}
+    assert temp["value"] == temp_bytes(
+        tr.step_plain, tr.params, adam_init(tr.params), batch,
+        jax.random.PRNGKey(0), {}) > 0
+    assert temp["t"] < split
     for name in ("client/h2d", "client/dispatch", "client/loss_sync"):
         assert len(by[name]) == len(trains) * steps, name
         for sp in by[name]:
@@ -273,15 +288,16 @@ def test_sequential_round_client_and_edge_spans(tmp_path):
 
 
 def test_spans_reach_the_profiler_trace(tmp_path):
-    """Spans of both tracers land on a host plane of the profiler's
-    own trace, with their attributes, whether or not the JSON-lines
-    sink is on."""
+    """Spans of both tracers, and the enabled tracer's counters, land on
+    a host plane of the profiler's own trace, with their attributes,
+    whether or not the JSON-lines sink is on."""
     from jax.profiler import ProfileData
     tracer = Tracer(str(tmp_path / "p.jsonl"))
     with jax.profiler.trace(str(tmp_path / "prof")):
         with tracer.span("round/dispatch", round=4):
-            with NULL_TRACER.span("client/step"):
+            with NULL_TRACER.span("client/step", remat="policy"):
                 jax.numpy.ones(2).block_until_ready()
+            tracer.counter("step/temp_bytes", 7, remat="policy")
     tracer.close()
     (path,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile"
                             / "*" / "*.xplane.pb"))
@@ -292,6 +308,9 @@ def test_spans_reach_the_profiler_trace(tmp_path):
                 for ev in line.events:
                     host[ev.name.split("#")[0]] = (dict(ev.stats), ev)
     assert host["round/dispatch"][0]["round"] == 4
+    assert host["client/step"][0]["remat"] == "policy"
+    assert (host["step/temp_bytes"][0]["value"],
+            host["step/temp_bytes"][0]["remat"]) == (7, "policy")
     outer, inner = host["round/dispatch"][1], host["client/step"][1]
     assert outer.start_ns <= inner.start_ns
     assert (inner.start_ns + inner.duration_ns

@@ -4,7 +4,10 @@ Nothing runs: the TPU compiler, installed with JAX, compiles for a
 ``v5e:2x2`` topology that is described, not attached, and refuses what
 the chip would refuse (illegal block shapes, unlowerable primitives,
 too much VMEM).  Shapes are the paper-width CIFAR-10 U-Net's, alone and
-vmapped over two clients as the round engine runs them.
+vmapped over two clients as the round engine runs them.  One whole
+step is compiled too: the CelebA U-Net's batch-32 sparse-phase step as
+the FedPhD trainer builds it, whose temporaries have to leave room on
+the chip for what a round keeps resident.
 
 The topology is described inside a module fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker
@@ -88,3 +91,33 @@ def test_flash_attention_compiles_at_unet_attention(one_chip, clients):
         q, k, v, causal=False, interpret=False),
         [shape, shape, shape], one_chip, clients)
     assert "tpu_custom_call" in text
+
+
+def test_celeba_step_fits_under_remat(one_chip):
+    """The 64x64 U-Net's sparse-phase step (batch 32, float32, Adam and
+    the group-lasso term), as ``FedPhD`` builds it, holds at most 7 GiB
+    of temporaries on one v5e under its ``remat_policy`` (18.9 GiB
+    without, more than the chip's 16)."""
+    from repro.configs import CELEBA_UNET
+    from repro.configs.base import FLConfig
+    from repro.core.hfl import FedPhD
+    from repro.data import ClientData
+    from repro.fl.client import Client
+
+    imgs = np.zeros((4, 64, 64, 3), np.float32)
+    clients = [Client(i, ClientData(imgs, np.arange(4) % 2, batch_size=4,
+                                    seed=i), 2) for i in range(2)]
+    tr = FedPhD(CELEBA_UNET.replace(backend="xla"),
+                FLConfig(num_clients=2, num_edges=1), clients,
+                engine="sequential")
+    shape = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    batch = {"images": jax.ShapeDtypeStruct((32, 64, 64, 3), jnp.float32,
+                                            sharding=one_chip),
+             "labels": jax.ShapeDtypeStruct((32,), jnp.int32,
+                                            sharding=one_chip)}
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    mem = tr.step_sparse.lower(
+        jax.tree.map(shape, tr.params), jax.tree.map(shape, tr._opt_zero),
+        batch, rng, {}).compile().memory_analysis()
+    assert mem.temp_size_in_bytes <= 7 * 2**30, mem.temp_size_in_bytes
